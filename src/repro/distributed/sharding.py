@@ -31,7 +31,7 @@ DEFAULT_RULES: dict[str, tuple[str, ...]] = {
     "d_inner": ("model",),
     "experts": ("model",),
     "vocab": ("model",),
-    "kv_seq": ("model",),     # sequence-sharded decode KV (used when heads don't divide)
+    "kv_seq": ("model",),     # sequence-sharded decode KV (in LAST_PICK, below)
     "fsdp": ("data",),        # ZeRO-3-style second param axis (arctic-class models
                               # cannot fit on a 16-way model axis alone)
     "act_seq": ("model",),    # sequence-parallel residual stream (Megatron-SP style)
@@ -40,6 +40,11 @@ DEFAULT_RULES: dict[str, tuple[str, ...]] = {
     "seq": (),
     "state": (),
 }
+
+# logical names that pick a mesh axis only after every other dim of the tensor has
+# had its pick: decode KV shards over heads where they divide the model axis (the
+# Pallas decode kernels run per head shard) and over positions only otherwise
+LAST_PICK: frozenset[str] = frozenset({"kv_seq"})
 
 
 class _Ctx(threading.local):
@@ -75,7 +80,8 @@ def logical_pspec(shape: Sequence[int], dims: Sequence[Optional[str]],
 
     A mesh axis is assigned to a dim only if (a) the rules map the logical name to it,
     (b) the axis exists in the mesh, (c) the dim size is divisible by the (product of)
-    axis size(s), and (d) the axis is not already used by an earlier dim.
+    axis size(s), and (d) the axis is not already used by an earlier dim.  Dims named
+    in ``LAST_PICK`` come after all the others.
     """
     mesh = mesh or _CTX.mesh
     rules = rules or _CTX.rules
@@ -83,8 +89,11 @@ def logical_pspec(shape: Sequence[int], dims: Sequence[Optional[str]],
         return P(*([None] * len(shape)))
     sizes = _mesh_axis_sizes(mesh)
     used: set[str] = set()
-    spec: list = []
-    for dim_size, logical in zip(shape, dims):
+    n = min(len(shape), len(dims))
+    spec: list = [None] * n
+    order = sorted(range(n), key=lambda i: dims[i] in LAST_PICK)
+    for i in order:
+        dim_size, logical = shape[i], dims[i]
         assigned = None
         if logical is not None:
             axes = tuple(a for a in rules.get(logical, ()) if a in sizes)
@@ -106,7 +115,7 @@ def logical_pspec(shape: Sequence[int], dims: Sequence[Optional[str]],
                             assigned = a
                             used.add(a)
                             break
-        spec.append(assigned)
+        spec[i] = assigned
     return P(*spec)
 
 

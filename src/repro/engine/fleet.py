@@ -26,6 +26,7 @@ CPU tier-1 environment — every worker falls back to un-meshed execution while
 the *declared* degrees keep driving the control plane, so heterogeneous
 scheduling remains testable on one device and becomes physically real under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (CI) or on actual pods.
+On a TPU such an mp>1 fleet is an error instead (``carve_worker_meshes``).
 """
 
 from __future__ import annotations

@@ -2,12 +2,13 @@
 
 The rollout hot spot Heddle's resource manager accelerates is decode-phase attention
 against a long KV cache.  This kernel implements the TPU-native adaptation: the KV cache
-streams HBM -> VMEM in ``block_c``-sized tiles (BlockSpec), the (G x hd) query tile stays
-resident in VMEM, and an online-softmax accumulator lives in VMEM scratch across the
-sequential kv-block grid axis.  GQA is handled by grouping the G query heads of one KV
-head into a single (G, hd) x (hd, block_c) MXU matmul — no KV replication.
+streams HBM -> VMEM in tiles of ``block_c`` positions (or one page) x all KV heads, the
+(KV, G, hd) query tile stays resident in VMEM, and per-head online-softmax accumulators
+live in VMEM scratch across the sequential kv-block grid axis.  GQA is handled by grouping
+the G query heads of one KV head into a single (G, hd) x (hd, tile) MXU matmul — no KV
+replication.
 
-Grid: (B, KV, num_kv_blocks); the last axis is sequential on TPU, enabling accumulation.
+Grid: (B, num_kv_blocks); the last axis is sequential on TPU, enabling accumulation.
 """
 
 from __future__ import annotations
@@ -24,41 +25,52 @@ F32 = jnp.float32
 DEFAULT_BLOCK_C = 512
 
 
-def _decode_attn_kernel(vlen_ref, q_ref, k_ref, v_ref, o_ref,
-                        m_ref, l_ref, acc_ref, *, block_c: int, num_blocks: int):
-    blk = pl.program_id(2)
+def _flash_decode_tile(step, n_steps, tile_len: int, vlen, q_ref, k_ref, v_ref,
+                       o_ref, m_ref, l_ref, acc_ref):
+    """Fold one KV tile into every head's online-softmax state.
 
-    @pl.when(blk == 0)
+    ``k_ref``/``v_ref`` hold ``(1, tile_len, KV, hd)``: all KV heads of
+    ``tile_len`` positions.  The TPU block must span the array's full (KV, hd)
+    minor dims — a one-head ``(.., 1, hd)`` block is rejected by the Mosaic
+    tiling rule — so the block carries every head and a static loop walks them.
+    Each head's (G, hd) query group meets its (tile_len, hd) keys in one matmul.
+    """
+
+    @pl.when(step == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    b = pl.program_id(0)
-    q = q_ref[0, 0].astype(F32)                      # (G, hd)
-    k = k_ref[0, :, 0].astype(F32)                   # (block_c, hd)
-    v = v_ref[0, :, 0].astype(F32)                   # (block_c, hd)
-    hd = q.shape[-1]
+    KV, hd = q_ref.shape[1], q_ref.shape[3]
     scale = 1.0 / math.sqrt(hd)
+    for h in range(KV):
+        q = q_ref[0, h].astype(F32)                  # (G, hd)
+        k = k_ref[0, :, h, :].astype(F32)            # (tile_len, hd)
+        v = v_ref[0, :, h, :].astype(F32)            # (tile_len, hd)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=F32) * scale  # (G, tile_len)
+        pos = step * tile_len + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < vlen, s, -1e30)
+        m_prev = m_ref[h]                             # (G, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)                        # (G, tile_len)
+        corr = jnp.exp(m_prev - m_new)                # (G, 1)
+        l_ref[h] = l_ref[h] * corr + p.sum(axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=F32)
+        m_ref[h] = m_new
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=F32) * scale   # (G, block_c)
-    vlen = vlen_ref[b]
-    pos = blk * block_c + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(pos < vlen, s, -1e30)
-
-    m_prev = m_ref[...]                               # (G, 1)
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)                            # (G, block_c)
-    corr = jnp.exp(m_prev - m_new)                    # (G, 1)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=F32)
-    m_ref[...] = m_new
-
-    @pl.when(blk == num_blocks - 1)
+    @pl.when(step == n_steps - 1)
     def _finish():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+def _decode_attn_kernel(vlen_ref, q_ref, k_ref, v_ref, o_ref,
+                        m_ref, l_ref, acc_ref, *, block_c: int, num_blocks: int):
+    _flash_decode_tile(pl.program_id(1), num_blocks, block_c,
+                       vlen_ref[pl.program_id(0)], q_ref, k_ref, v_ref, o_ref,
+                       m_ref, l_ref, acc_ref)
 
 
 def _paged_decode_attn_kernel(pt_ref, vlen_ref, q_ref, k_ref, v_ref, o_ref,
@@ -73,39 +85,15 @@ def _paged_decode_attn_kernel(pt_ref, vlen_ref, q_ref, k_ref, v_ref, o_ref,
     materialized.  Pages past the lane's resident length resolve to block 0
     (scratch); their scores are masked to -1e30 like any tail padding.
     """
-    i = pl.program_id(2)
+    _flash_decode_tile(pl.program_id(1), num_pages, page_size,
+                       vlen_ref[pl.program_id(0)], q_ref, k_ref, v_ref, o_ref,
+                       m_ref, l_ref, acc_ref)
 
-    @pl.when(i == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    b = pl.program_id(0)
-    q = q_ref[0, 0].astype(F32)                      # (G, hd)
-    k = k_ref[0, :, 0].astype(F32)                   # (page_size, hd)
-    v = v_ref[0, :, 0].astype(F32)                   # (page_size, hd)
-    hd = q.shape[-1]
-    scale = 1.0 / math.sqrt(hd)
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=F32) * scale  # (G, page_size)
-    vlen = vlen_ref[b]
-    pos = i * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(pos < vlen, s, -1e30)
-
-    m_prev = m_ref[...]                               # (G, 1)
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)                            # (G, page_size)
-    corr = jnp.exp(m_prev - m_new)                    # (G, 1)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=F32)
-    m_ref[...] = m_new
-
-    @pl.when(i == num_pages - 1)
-    def _finish():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+def _scratch(KV: int, G: int, hd: int) -> list:
+    return [pltpu.VMEM((KV, G, 1), F32),              # running max m
+            pltpu.VMEM((KV, G, 1), F32),              # running denom l
+            pltpu.VMEM((KV, G, hd), F32)]             # output accumulator
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -128,26 +116,17 @@ def paged_decode_attention_pallas(q: jax.Array, k_pool: jax.Array,
 
     kernel = functools.partial(_paged_decode_attn_kernel, page_size=page_size,
                                num_pages=num_pages)
-    grid = (B, KV, num_pages)
+    q_spec = pl.BlockSpec((1, KV, G, hd), lambda b, i, pt, vl: (b, 0, 0, 0))
+    kv_spec = pl.BlockSpec((1, page_size, KV, hd),
+                           lambda b, i, pt, vl: (pt[b, i], 0, 0, 0))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, G, hd), lambda b, h, i, pt, vl: (b, h, 0, 0)),
-                pl.BlockSpec((1, page_size, 1, hd),
-                             lambda b, h, i, pt, vl: (pt[b, i], 0, h, 0)),
-                pl.BlockSpec((1, page_size, 1, hd),
-                             lambda b, h, i, pt, vl: (pt[b, i], 0, h, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, G, hd),
-                                   lambda b, h, i, pt, vl: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G, 1), F32),       # running max m
-                pltpu.VMEM((G, 1), F32),       # running denom l
-                pltpu.VMEM((G, hd), F32),      # output accumulator
-            ],
+            grid=(B, num_pages),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            scratch_shapes=_scratch(KV, G, hd),
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         interpret=interpret,
@@ -172,23 +151,16 @@ def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
 
     kernel = functools.partial(_decode_attn_kernel, block_c=block_c,
                                num_blocks=num_blocks)
-    grid = (B, KV, num_blocks)
+    q_spec = pl.BlockSpec((1, KV, G, hd), lambda b, c, vl: (b, 0, 0, 0))
+    kv_spec = pl.BlockSpec((1, block_c, KV, hd), lambda b, c, vl: (b, c, 0, 0))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, G, hd), lambda b, h, c, vl: (b, h, 0, 0)),
-                pl.BlockSpec((1, block_c, 1, hd), lambda b, h, c, vl: (b, c, h, 0)),
-                pl.BlockSpec((1, block_c, 1, hd), lambda b, h, c, vl: (b, c, h, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, c, vl: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G, 1), F32),       # running max m
-                pltpu.VMEM((G, 1), F32),       # running denom l
-                pltpu.VMEM((G, hd), F32),      # output accumulator
-            ],
+            grid=(B, num_blocks),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            scratch_shapes=_scratch(KV, G, hd),
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         interpret=interpret,

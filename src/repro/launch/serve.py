@@ -10,6 +10,10 @@ Local (real execution, reduced model):
     PYTHONPATH=src python -m repro.launch.serve --requests 16 --steps 3 \
         --scheduler pps [--migration on|off] [--tool-latency 1.0]
 
+On a TPU chip, at the model's published widths and dtype (no ``.reduced()``):
+    PYTHONPATH=src python -m repro.launch.serve --published --requests 8 \
+        --group-size 4 --steps 3 --max-tokens 256 --capacity 1024
+
 Open-loop serving (Poisson ingress, tenant SLOs, admission control):
     PYTHONPATH=src python -m repro.launch.serve --requests 24 --arrival poisson \
         --qps 4 --tenants 'gold:0.25:30,best:0.75:10' [--admission on|off]
@@ -166,9 +170,13 @@ def _run_service(args, runtime):
     return 0
 
 
-def main(argv=None):
+def make_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="qwen3-1.7b")
+    ap.add_argument("--published", action="store_true",
+                    help="serve the architecture at its published widths and "
+                         "dtype (the chip configuration); default is the "
+                         "reduced float32 model that runs on a CPU")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--group-size", type=int, default=4,
                     help="GRPO group size: requests per shared prompt (prefix-"
@@ -237,6 +245,24 @@ def main(argv=None):
     ap.add_argument("--shape", default="decode_32k",
                     choices=["prefill_32k", "decode_32k", "long_500k"])
     ap.add_argument("--multi-pod", action="store_true")
+    return ap
+
+
+def load_model(args):
+    """The served model and its seeded random weights: ``get_config(arch)`` as
+    published with ``--published``, else its reduced CPU variant."""
+    import jax
+    from repro.configs import get_config
+    from repro.models import model as M
+
+    cfg = get_config(args.arch)
+    if not args.published:
+        cfg = cfg.reduced(n_periods=2)
+    return cfg, M.init_params(cfg, jax.random.PRNGKey(args.seed))
+
+
+def main(argv=None):
+    ap = make_parser()
     args = ap.parse_args(argv)
     _validate_args(ap, args)
 
@@ -248,17 +274,19 @@ def main(argv=None):
         return dryrun.main(dr_args)
 
     import jax
-    from repro.configs import get_config
-    from repro.models import model as M
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     if args.degrees:
         degrees = [int(d) for d in args.degrees.split(",")]
         if max(degrees) > len(jax.devices()):
             ap.error(f"--degrees asks for an MP-{max(degrees)} worker but only "
                      f"{len(jax.devices())} device(s) are visible")
 
-    cfg = get_config(args.arch).reduced(n_periods=2)
-    params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
+    cfg, params = load_model(args)
+    print(f"model {cfg.name} ({'published' if args.published else 'reduced'}): "
+          f"{cfg.n_layers} layers, d_model {cfg.d_model}, {cfg.dtype}, on "
+          f"{len(jax.devices())} {jax.devices()[0].device_kind} device(s)")
     runtime = build_runtime(args, cfg, params)
     controller = runtime.controller
 

@@ -42,9 +42,11 @@ def main(argv=None):
 
     from repro.checkpoint import checkpoint as ckpt
     from repro.configs import get_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.rl import data as D
     from repro.rl.loop import HeddleTrainer, TrainerConfig
 
+    enable_compile_cache()
     cfg = get_config(args.arch).reduced(n_periods=2)
     trainer = HeddleTrainer(cfg, TrainerConfig(
         group_size=args.group_size, n_workers=args.workers, lr=args.lr,

@@ -65,11 +65,30 @@ def test_fleet_spec_from_allocation():
 
 
 def test_carve_worker_meshes_falls_back_without_devices():
-    # a device list too small for the budget degrades every worker to un-meshed
+    # off-TPU, a device list too small for the budget degrades every worker to
+    # un-meshed (the declared degrees still drive the control plane)
     meshes = carve_worker_meshes([4, 2, 1, 1], jax.devices()[:1])
     assert meshes == [None] * 4
-    # an all-mp1 fleet never builds meshes (nothing to shard)
-    assert carve_worker_meshes([1, 1], jax.devices()) == [None, None]
+    # several mp1 workers on one device share it; a lone mp1 worker is covered
+    assert carve_worker_meshes([1, 1], jax.devices()[:1]) == [None, None]
+    [lone] = carve_worker_meshes([1], jax.devices()[:1])
+    assert lone.devices.shape == (1, 1) and lone.devices.flat[0] == jax.devices()[0]
+
+
+def test_carve_worker_meshes_refuses_oversized_fleet_on_tpu():
+    """On a TPU an mp>1 fleet that does not fit would run silently unsharded."""
+    from types import SimpleNamespace
+    chip = SimpleNamespace(platform="tpu")
+    with pytest.raises(ValueError, match="need 3 devices"):
+        carve_worker_meshes([2, 1], [chip])
+    assert carve_worker_meshes([1, 1], [chip]) == [None, None]   # shared chip
+
+
+@pytest.mark.skipif(jax.device_count() < 2, reason="needs >=2 host devices")
+def test_carve_worker_meshes_gives_mp1_workers_their_own_devices():
+    meshes = carve_worker_meshes([1, 1], jax.devices()[:2])
+    assert [m.devices.shape for m in meshes] == [(1, 1), (1, 1)]
+    assert {m.devices.flat[0].id for m in meshes} == {d.id for d in jax.devices()[:2]}
 
 
 @pytest.mark.skipif(jax.device_count() < 8, reason="needs 8 host devices")
@@ -93,6 +112,21 @@ def test_meshed_decode_matches_unmeshed(setup):
     for w in (meshed, plain):
         w.prefill(0, PROMPT)
     assert meshed.decode([0], 16)[0] == plain.decode([0], 16)[0]
+
+
+@pytest.mark.skipif(jax.device_count() < 2, reason="needs >=2 host devices")
+def test_meshed_pallas_decode_matches_unmeshed(setup):
+    """The Pallas decode kernel runs per KV-head shard under an mp=2 mesh
+    (shard_map; interpret mode off-TPU) and decodes the unmeshed tokens."""
+    from dataclasses import replace
+    cfg, params = setup
+    cfg = replace(cfg, use_pallas_decode=True)
+    meshed = RolloutWorker(cfg, params, capacity=32, max_slots=2, sampler=GREEDY,
+                           mesh=_mesh(2), mp=2)
+    plain = RolloutWorker(cfg, params, capacity=32, max_slots=2, sampler=GREEDY)
+    for w in (meshed, plain):
+        w.prefill(0, PROMPT)
+    assert meshed.decode([0], 8)[0] == plain.decode([0], 8)[0]
 
 
 def test_cross_degree_migration_parity(setup):
@@ -206,12 +240,14 @@ def test_fleet_reconfigure_rebuilds_on_mesh_presence_change(setup):
     worker — reusing an un-meshed engine under a newly carved mesh would
     silently ignore the new sharding (and vice versa)."""
     cfg, params = setup
-    fleet = RolloutFleet(cfg, params, FleetSpec((2, 1)), capacity=32,
-                         max_slots=2, sampler=GREEDY)
-    report = fleet.reconfigure(FleetSpec((1, 1)))   # meshed fleet -> all-mp1
+    devices = jax.devices()[:2]
+    fleet = RolloutFleet(cfg, params, FleetSpec((1, 1)), capacity=32,
+                         max_slots=2, sampler=GREEDY, devices=devices)
+    had_meshes = len(devices) == 2                  # one device per mp1 worker
+    assert all((w.mesh is not None) == had_meshes for w in fleet.workers)
+    report = fleet.reconfigure(FleetSpec((2, 1)))   # needs 3 devices: un-meshed
     if any(w.mesh is not None for w in fleet.workers):
-        pytest.fail("all-mp1 fleet must be un-meshed")
-    had_meshes = jax.device_count() >= 3            # (2,1) was physically meshed
+        pytest.fail("a fleet the devices cannot cover must be un-meshed")
     if had_meshes:
         assert report["rebuilt"] == [0, 1]          # both crossed out of meshes
     else:

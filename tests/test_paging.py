@@ -164,6 +164,22 @@ def test_paged_kernel_ignores_unmapped_and_invalid_blocks():
     np.testing.assert_allclose(np.asarray(base), np.asarray(out), atol=1e-5)
 
 
+def test_meshed_pallas_decode_rejects_indivisible_kv_heads():
+    """Under a worker mesh the Pallas kernels run per KV-head shard; a model axis
+    the heads do not divide (smollm's 3 KV heads at mp 2) is an error, not a
+    replicated run that all-gathers the pool onto every device."""
+    from types import SimpleNamespace
+
+    from repro.distributed.sharding import axis_rules
+    mesh = SimpleNamespace(size=2, shape={"data": 1, "model": 2})
+    B, KV, G, hd, ps = 1, 3, 3, 64, 8
+    q = jnp.zeros((B, KV, G, hd))
+    pool = jnp.zeros((3, ps, KV, hd))
+    pt, vl = jnp.asarray([[1, 2]], jnp.int32), jnp.asarray([9], jnp.int32)
+    with axis_rules(mesh), pytest.raises(ValueError, match="3 KV heads do not divide"):
+        ops.paged_decode_attention(q, pool, pool, pt, vl, force_pallas=True)
+
+
 # ------------------------------------------------------- bitwise token parity
 
 def test_paged_decode_bitwise_matches_dense(setup):

@@ -56,6 +56,16 @@ def test_cache_pspecs_cover_every_leaf():
         assert len(leaves_s) == len(jax.tree.leaves(cache))
 
 
+def test_kv_caches_shard_heads_before_positions():
+    """KV heads take the model axis when they divide it (the Pallas decode
+    kernels then run per head shard); positions take it only otherwise."""
+    from types import SimpleNamespace
+    mesh = SimpleNamespace(axis_names=("data", "model"), devices=np.empty((1, 2)))
+    dims = ("batch", "kv_seq", "kv_heads", None)
+    assert logical_pspec((4, 16, 8, 128), dims, mesh=mesh) == P(None, None, "model", None)
+    assert logical_pspec((4, 16, 3, 64), dims, mesh=mesh) == P(None, "model", None, None)
+
+
 def test_shard_is_identity_without_mesh():
     x = jnp.ones((4, 8))
     y = shard(x, ("batch", "d_ff"))

@@ -240,7 +240,9 @@ def test_chunk_window_past_capacity_edge_stays_exact(setup):
     key to its absolute slot — a clamping slice-write would smear the tail chunk
     over resident positions.  Pins the dense (``paged=False``) lane layout the
     raw-KV comparison below assumes; the paged twin of this edge lives in
-    tests/test_paging.py (page-boundary straddling)."""
+    tests/test_paging.py (page-boundary straddling).  The two paths compute
+    the same keys through differently fused programs, so they agree to float
+    rounding (~1e-6); a smeared slot would be off by O(1)."""
     cfg, params = setup
     sampler = SamplerConfig(temperature=1.0, top_p=0.9)
     w = RolloutWorker(cfg, params, capacity=16, max_slots=2, sampler=sampler,
@@ -255,7 +257,7 @@ def test_chunk_window_past_capacity_edge_stays_exact(setup):
         for key in ("k", "v"):
             got = np.asarray(blk[key])
             want = np.asarray(legacy.store[1].cache["blocks"][name][key])
-            np.testing.assert_array_equal(got, want)
+            np.testing.assert_allclose(got, want, rtol=0, atol=1e-5)
     assert w.decode([1], 1) == legacy.decode([1], 1)
 
 

@@ -76,6 +76,25 @@ def test_dryrun_single_combo_subprocess():
     assert rec["collective_total_bytes"] >= 0
 
 
+@pytest.mark.parametrize("env", ["", "/shared/jax-cache"])
+def test_compile_cache_dir_is_the_env_or_the_checkout(monkeypatch, env):
+    """``JAX_COMPILATION_CACHE_DIR`` is left to JAX; otherwise the cache sits at a
+    fixed ``<checkout>/.jax_cache``.  Either way lowered programs carry no source
+    locations, so their cache keys do not depend on the checkout's path."""
+    from repro.launch import compile_cache
+    updates = {}
+    monkeypatch.setattr(compile_cache.jax.config, "update",
+                        lambda name, value: updates.__setitem__(name, value))
+    if env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    want = env or os.path.join(REPO, ".jax_cache")
+    assert compile_cache.enable_compile_cache() == want
+    assert updates.get("jax_compilation_cache_dir") == (None if env else want)
+    assert updates["jax_traceback_in_locations_limit"] == 0
+
+
 def test_roofline_reader_on_committed_dryrun_artifacts():
     path = os.path.join(REPO, "dryrun_16x16.json")
     if not os.path.exists(path):
