@@ -27,7 +27,6 @@ shared orchestrator loop, is what makes sim-vs-engine decision traces equal.
 from __future__ import annotations
 
 import math
-import time
 from typing import Callable, Optional, Sequence
 
 import numpy as np
@@ -474,7 +473,6 @@ class EngineBackend:
         self._step_gen: dict[int, list[int]] = {}  # token ids decoded this step
         self._gen_time: dict[int, float] = {}
         self.total_tokens = 0  # real tokens decoded across all workers
-        self.wall = 0.0  # real seconds spent in the data plane
         # failure realism: tool-boundary checkpoints (host-gathered lane
         # packages in migrate_out format) + dead-worker bookkeeping
         self.checkpoint_dir = checkpoint_dir
@@ -501,14 +499,12 @@ class EngineBackend:
         for view in self.views:
             mine = [t for t in trajectories if t.worker_id == view.wid]
             mine.sort(key=lambda t: (t.prompt_id, t.sample_id))
-            t0 = time.perf_counter()
             for t in mine:
                 toks = self.prompts[t.traj_id]
                 view.engine.prefill(t.traj_id, toks)
                 view.clock = max(view.clock, now) + admission_seconds(
                     len(toks), view.token_time, self.prefill_speedup
                 )
-            self.wall += time.perf_counter() - t0
 
     def ready_time(self, wid: int, now: float) -> float:
         return max(now, self.views[wid].clock)
@@ -535,9 +531,7 @@ class EngineBackend:
             return []
         ids, q, end, dt = view.plan
         view.plan = None
-        t0 = time.perf_counter()
         out = view.engine.decode(ids, q, stop_token=self.stop_token)
-        self.wall += time.perf_counter() - t0
         view.clock = end
         done = []
         for tid in ids:
@@ -589,9 +583,7 @@ class EngineBackend:
         self.last_absorb.pop(traj.traj_id, None)
         if toks:  # chunked prefill into the lane, wherever it lives now
             view = self.views[traj.worker_id]
-            t0 = time.perf_counter()
             view.engine.extend(traj.traj_id, toks)
-            self.wall += time.perf_counter() - t0
             self.last_absorb[traj.traj_id] = list(toks)
 
     def can_migrate(self, traj: Trajectory) -> bool:
@@ -601,17 +593,13 @@ class EngineBackend:
         import jax  # local: backends must import without initializing jax early
 
         src = self.views[traj.worker_id]
-        t0 = time.perf_counter()
         pkg = src.engine.migrate_out(traj.traj_id)
-        self.wall += time.perf_counter() - t0
         self.in_transit[traj.traj_id] = pkg
         return migration_time(_package_bytes(pkg, jax), self.link_bandwidth)
 
     def migrate_in(self, traj: Trajectory, dst: int) -> None:
         pkg = self.in_transit.pop(traj.traj_id)
-        t0 = time.perf_counter()
         self.views[dst].engine.migrate_in(pkg)  # lane lands in the new pool
-        self.wall += time.perf_counter() - t0
 
     def release(self, traj: Trajectory) -> None:
         """Finished (or shed): the lane retires into the radix cache (prefix
@@ -640,9 +628,7 @@ class EngineBackend:
         view = self.views[traj.worker_id]
         if tid not in view.engine.store:
             return  # lane already on the wire; the transfer carries the state
-        t0 = time.perf_counter()
         pkg = view.engine.checkpoint_out(tid)
-        self.wall += time.perf_counter() - t0
         self.ckpts[tid] = pkg
         self.last_absorb.pop(tid, None)  # the new snapshot includes it
         if self.checkpoint_dir:
@@ -686,16 +672,12 @@ class EngineBackend:
         pkg = self.ckpts.get(tid)
         if pkg is None:
             toks = self.prompts[tid]
-            t0 = time.perf_counter()
             view.engine.prefill(tid, toks)
-            self.wall += time.perf_counter() - t0
             return admission_seconds(len(toks), view.token_time, self.prefill_speedup)
-        t0 = time.perf_counter()
         view.engine.migrate_in(dict(pkg))
         extra = self.last_absorb.get(tid)
         if extra:  # tool output absorbed after the snapshot: replay it
             view.engine.extend(tid, extra)
-        self.wall += time.perf_counter() - t0
         return migration_time(_package_bytes(pkg, jax), self.link_bandwidth)
 
     def kill(self, wid: int) -> None:

@@ -30,7 +30,6 @@ concat/slice engine as the parity reference; see docs/engine.md for invariants.
 
 from __future__ import annotations
 
-import time
 from collections import OrderedDict
 from dataclasses import dataclass
 from functools import partial
@@ -44,6 +43,7 @@ from repro.distributed.sharding import (axis_rules, cache_shardings,
                                         param_shardings)
 from repro.engine.paging import PagePool, PagePoolExhausted
 from repro.engine.sampler import SamplerConfig, sample_slots
+from repro.engine.spans import SpanTotals
 from repro.models import model as M
 from repro.models.config import ModelConfig
 
@@ -467,9 +467,10 @@ class RolloutWorker:
         self.prefilled_tokens = 0                  # admission tokens actually computed
         self.absorbed_tokens = 0                   # tool tokens teacher-forced (extend)
         self.prefill_dispatches = 0                # chunk kernel launches
-        # measured decode timing (feeds WorkerLatencyModel calibration, §6).
-        # Only WARM calls are timed — a call that grew the jit cache spent
-        # seconds compiling, and meshed workers pay per-mesh compiles that
+        self.spans = SpanTotals()                  # host spans (engine/spans.py)
+        # measured decode timing (feeds WorkerLatencyModel calibration, §6): the
+        # ``decode`` span of WARM calls only — a call that grew the jit cache
+        # spent seconds compiling, and meshed workers pay per-mesh compiles that
         # un-meshed ones share, so compile-polluted samples would make mp>1
         # look slower than it is.  wall_s / timed_steps is the observed
         # per-STEP decode time (the full-pool masked kernel's cost is
@@ -622,45 +623,67 @@ class RolloutWorker:
     # ------------------------------------------------------------ lifecycle
     def prefill(self, seq_id: int, tokens: list[int]) -> None:
         """Admit a sequence: implant any radix-matched prefix from a resident or
-        retired lane (O(1) on-device slice copy), then chunk-prefill the suffix."""
-        S = len(tokens)
-        reuse_n, src = 0, None
-        if self._reuse:
-            reuse_n, src = self.prefix_index.match_lane(tokens)
-        else:
-            self.prefix_index.match_len(tokens)
-        slot = self._alloc_slot()
-        if self._paged:
-            self._prefill_paged(slot, tokens, reuse_n, src)
-        elif not self._chunked:
-            arr = jnp.asarray(tokens, jnp.int32)[None]
-            self.pool = _admit(self.cfg, self.params, self.pool, arr, slot,
-                               self.capacity, mesh=self.mesh)
-            self.prefilled_tokens += S
-        else:
-            lane = self._new_lane()
-            if src is not None and reuse_n > 0:
-                if src in self.retired:
-                    self.retired.move_to_end(src)         # LRU touch
-                lane = _copy_prefix(self.pool, jnp.asarray(src, jnp.int32), lane,
-                                    jnp.asarray(reuse_n, jnp.int32))
-                self.reused_tokens += reuse_n
-            lane = self._chunk_into(lane, tokens, reuse_n)
-            self.pool = _implant(self.pool, lane, slot)
-            self.prefilled_tokens += S - reuse_n
-        key = np.asarray(jax.random.fold_in(self.base_key, seq_id))
-        self.store[seq_id] = Sequence(seq_id, list(tokens), slot, key)
-        self.prefix_index.insert(tokens, slot=slot)
+        retired lane (O(1) on-device slice copy), then chunk-prefill the suffix.
 
-    def _prefill_paged(self, slot: int, tokens: list[int], reuse_n: int,
-                       src: int | None) -> None:
-        """Paged admission: share the matched prefix's full pages by refcount
-        (zero KV copy), D2D-copy its boundary partial page, then chunk-prefill
-        the suffix straight into freshly mapped pages.
+        Each step of admission runs in a span of its own, children of
+        ``prefill`` (docs/engine.md, "Spans and counters")."""
+        S = len(tokens)
+        span = partial(self.spans.span, seq_id=seq_id)
+        with span("prefill"):
+            with span("radix_match"):
+                reuse_n, src = 0, None
+                if self._reuse:
+                    reuse_n, src = self.prefix_index.match_lane(tokens)
+                else:
+                    self.prefix_index.match_len(tokens)
+            with span("map_pages"):
+                slot = self._alloc_slot()
+                if self._paged:
+                    reuse_n = self._map_paged(slot, S, reuse_n, src)
+            if self._paged and self._chunked:
+                if reuse_n < S:
+                    with span("chunk_dispatch"):
+                        self._chunk_into_paged(slot, tokens, reuse_n)
+                self.prefilled_tokens += S - reuse_n
+            elif self._paged:
+                arr = jnp.asarray(tokens, jnp.int32)[None]
+                self.pool = _admit_paged(self.cfg, self.params, self.pool, arr, slot,
+                                         self._row_of(self.lane_pages[slot]), S,
+                                         mesh=self.mesh)
+                self.prefilled_tokens += S
+            elif not self._chunked:
+                arr = jnp.asarray(tokens, jnp.int32)[None]
+                self.pool = _admit(self.cfg, self.params, self.pool, arr, slot,
+                                   self.capacity, mesh=self.mesh)
+                self.prefilled_tokens += S
+            else:
+                lane = self._new_lane()
+                if src is not None and reuse_n > 0:
+                    if src in self.retired:
+                        self.retired.move_to_end(src)     # LRU touch
+                    lane = _copy_prefix(self.pool, jnp.asarray(src, jnp.int32), lane,
+                                        jnp.asarray(reuse_n, jnp.int32))
+                    self.reused_tokens += reuse_n
+                if reuse_n < S:
+                    with span("chunk_dispatch"):
+                        lane = self._chunk_into(lane, tokens, reuse_n)
+                self.pool = _implant(self.pool, lane, slot)
+                self.prefilled_tokens += S - reuse_n
+            with span("seq_key"):
+                key = np.asarray(jax.random.fold_in(self.base_key, seq_id))
+            self.store[seq_id] = Sequence(seq_id, list(tokens), slot, key)
+            with span("radix_insert"):
+                self.prefix_index.insert(tokens, slot=slot)
+
+    def _map_paged(self, slot: int, S: int, reuse_n: int, src: int | None) -> int:
+        """Map lane ``slot``'s pages for an ``S``-token admission: share the
+        matched prefix's full pages by refcount (zero KV copy), D2D-copy its
+        boundary partial page, and map fresh pages for the suffix.  Returns the
+        positions the lane already holds, where the chunk prefill starts.
 
         Warm GRPO siblings therefore pay page-table rows + O(suffix) compute —
         the dense path's O(reuse_n) lane-slice copy disappears entirely."""
-        S, ps = len(tokens), self.page_size
+        ps = self.page_size
         blocks: list[int] = []
         boundary: tuple[int, int] | None = None
         reuse_eff = 0
@@ -688,14 +711,7 @@ class RolloutWorker:
         if boundary is not None:
             self.pool = _copy_block(self.pool, jnp.asarray(boundary[0], jnp.int32),
                                     jnp.asarray(boundary[1], jnp.int32))
-        if not self._chunked:
-            arr = jnp.asarray(tokens, jnp.int32)[None]
-            self.pool = _admit_paged(self.cfg, self.params, self.pool, arr, slot,
-                                     self._row_of(blocks), S, mesh=self.mesh)
-            self.prefilled_tokens += S
-            return
-        self._chunk_into_paged(slot, tokens, reuse_eff)
-        self.prefilled_tokens += S - reuse_eff
+        return reuse_eff
 
     def _chunk_into(self, lane, tokens: list[int], start: int):
         """Feed ``tokens[start:]`` through the fixed-shape chunk kernel."""
@@ -808,35 +824,36 @@ class RolloutWorker:
         ran = 0
         lane_steps = 0
         cache0 = _decode_loop._cache_size()
-        t0 = time.perf_counter()
-        while remaining > 0:
-            step = min(chunk, remaining)
-            self.pool, last, live, em = _decode_loop(
-                self.cfg, self.params, self.pool, last, live, keys,
-                step, stop_token, self.sampler, mesh=self.mesh)
-            parts.append(em)   # device-resident: D2H deferred past the loop
-            remaining -= step
-            ran += step
-            self.decode_steps += step
-            if stop_token is None:                          # nothing stops early
-                lane_steps += step * len(requested)
-            else:
-                # live batch after the chunk: lanes stopping mid-call must not
-                # keep inflating the calibration's mean-batch regressor.  The
-                # sync is the point — it is the early-exit check that stops
-                # decoding once every requested lane hit its stop token.
-                n_live = int(np.asarray(live).sum())  # heddle: noqa HDL003 -- deliberate early-exit sync, one per chunk
-                lane_steps += step * n_live
-                if remaining > 0 and n_live == 0:
-                    break
-        wall = time.perf_counter() - t0
+        # the span ends once the emitted tokens are on the host: that transfer
+        # is the sync that holds the device time of every step dispatched
+        with self.spans.span("decode", lanes=len(requested)) as span:
+            while remaining > 0:
+                step = min(chunk, remaining)
+                self.pool, last, live, em = _decode_loop(
+                    self.cfg, self.params, self.pool, last, live, keys,
+                    step, stop_token, self.sampler, mesh=self.mesh)
+                parts.append(em)   # device-resident: D2H deferred past the loop
+                remaining -= step
+                ran += step
+                self.decode_steps += step
+                if stop_token is None:                          # nothing stops early
+                    lane_steps += step * len(requested)
+                else:
+                    # live batch after the chunk: lanes stopping mid-call must not
+                    # keep inflating the calibration's mean-batch regressor.  The
+                    # sync is the point — it is the early-exit check that stops
+                    # decoding once every requested lane hit its stop token.
+                    n_live = int(np.asarray(live).sum())  # heddle: noqa HDL003 -- deliberate early-exit sync, one per chunk
+                    lane_steps += step * n_live
+                    if remaining > 0 and n_live == 0:
+                        break
+            emitted = (np.concatenate([np.asarray(p) for p in parts], axis=0)
+                       if parts else np.zeros((0, B), np.int32))  # n_tokens == 0 edge
         if _decode_loop._cache_size() == cache0:            # warm: no compile inside
-            self.decode_wall_s += wall
+            self.decode_wall_s += span.ns * 1e-9
             self.decode_timed_steps += ran
             self.decode_timed_lane_steps += lane_steps
         self.decode_calls += 1
-        emitted = (np.concatenate([np.asarray(p) for p in parts], axis=0)
-                   if parts else np.zeros((0, B), np.int32))  # n_tokens == 0 edge
         out: dict[int, list[int]] = {sid: [] for sid in seq_ids}
         for sid in requested:
             seq = self.store[sid]
@@ -1083,4 +1100,5 @@ class RolloutWorker:
             "decode_timed_steps": self.decode_timed_steps,
             "decode_timed_lane_steps": self.decode_timed_lane_steps,
             "decode_calls": self.decode_calls,
+            **self.spans.stats(),
         }

@@ -270,34 +270,38 @@ def attention_prefill_chunk_paged(
     B, Cn, _ = x.shape
     KV, hd, H = cfg.n_kv_heads, cfg.hd, cfg.n_heads
     G = H // KV
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
-    k = jnp.einsum("bsd,dnk->bsnk", x, p["wk"])
-    v = jnp.einsum("bsd,dnk->bsnk", x, p["wv"])
-    if cfg.qk_norm:
-        q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
-        k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
-    positions = (off + jnp.arange(Cn))[None]                  # (1, C) absolute
-    if use_rope:
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+        k = jnp.einsum("bsd,dnk->bsnk", x, p["wk"])
+        v = jnp.einsum("bsd,dnk->bsnk", x, p["wv"])
+        if cfg.qk_norm:
+            q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
+            k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
+        positions = (off + jnp.arange(Cn))[None]              # (1, C) absolute
+        if use_rope:
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
     ps = k_pool.shape[1]
     num_pages = pt_row.shape[0]
     cap = num_pages * ps
     rows = off + jnp.arange(Cn)
-    valid = (jnp.arange(Cn) < length) & (rows < cap)
-    page = jnp.clip(rows // ps, 0, num_pages - 1)
-    blk = jnp.where(valid, pt_row[page], 0)                   # padding -> scratch
-    slot = rows % ps
-    k_pool = k_pool.at[blk, slot].set(k[0].astype(k_pool.dtype))
-    v_pool = v_pool.at[blk, slot].set(v[0].astype(v_pool.dtype))
-    kg = k_pool[pt_row][None].reshape(1, cap, KV, hd)
-    vg = v_pool[pt_row][None].reshape(1, cap, KV, hd)
-    mask = jnp.arange(cap)[None, :] <= rows[:, None]          # (C, cap)
-    qg = q.reshape(B, Cn, KV, G, hd)
-    out = _plain_attention(qg, kg, vg, mask[None, None, None],
-                           1.0 / math.sqrt(hd))
-    out = out.reshape(B, Cn, H, hd)
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), k_pool, v_pool
+    with jax.named_scope("kv_write"):
+        valid = (jnp.arange(Cn) < length) & (rows < cap)
+        page = jnp.clip(rows // ps, 0, num_pages - 1)
+        blk = jnp.where(valid, pt_row[page], 0)               # padding -> scratch
+        slot = rows % ps
+        k_pool = k_pool.at[blk, slot].set(k[0].astype(k_pool.dtype))
+        v_pool = v_pool.at[blk, slot].set(v[0].astype(v_pool.dtype))
+    with jax.named_scope("attn"):
+        kg = k_pool[pt_row][None].reshape(1, cap, KV, hd)
+        vg = v_pool[pt_row][None].reshape(1, cap, KV, hd)
+        mask = jnp.arange(cap)[None, :] <= rows[:, None]      # (C, cap)
+        qg = q.reshape(B, Cn, KV, G, hd)
+        out = _plain_attention(qg, kg, vg, mask[None, None, None],
+                               1.0 / math.sqrt(hd))
+        out = out.reshape(B, Cn, H, hd)
+        out = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+    return out, k_pool, v_pool
 
 
 def attention_prefill_chunk(

@@ -640,22 +640,23 @@ def prefill_chunk(cfg: ModelConfig, params, cache: dict, tokens: jax.Array,
     lane with ``pos`` advanced by ``length``.
     """
     assert tokens.shape[0] == 1, "prefill_chunk operates on one lane (batch 1)"
-    off = cache["pos"][0]
-    length = jnp.asarray(length, jnp.int32)
-    x = params["tok_embed"][tokens]
-    x = shard(x, ("batch", None, None))
+    with jax.named_scope("prefill_chunk"):
+        off = cache["pos"][0]
+        length = jnp.asarray(length, jnp.int32)
+        x = params["tok_embed"][tokens]
+        x = shard(x, ("batch", None, None))
 
-    def body(x, xs):
-        p_period, c_period = xs
-        new_c = {}
-        for i, kind in enumerate(cfg.block_pattern):
-            keyname = f"{i:02d}_{kind}"
-            x, new_c[keyname] = _layer_chunk(cfg, kind, p_period[keyname], x,
-                                             c_period[keyname], off, length)
-        return x, new_c
+        def body(x, xs):
+            p_period, c_period = xs
+            new_c = {}
+            for i, kind in enumerate(cfg.block_pattern):
+                keyname = f"{i:02d}_{kind}"
+                x, new_c[keyname] = _layer_chunk(cfg, kind, p_period[keyname], x,
+                                                 c_period[keyname], off, length)
+            return x, new_c
 
-    _, new_blocks = lax.scan(body, x, (params["blocks"], cache["blocks"]))
-    return {"pos": cache["pos"] + length, "blocks": new_blocks}
+        _, new_blocks = lax.scan(body, x, (params["blocks"], cache["blocks"]))
+        return {"pos": cache["pos"] + length, "blocks": new_blocks}
 
 
 def copy_prefix(pool: dict, src_slot, lane: dict, n) -> dict:
@@ -829,7 +830,8 @@ def init_paged_pool(cfg: ModelConfig, params, max_lanes: int, num_blocks: int,
 def _layer_chunk_paged(cfg, kind, p, x, cache, pt_row, slot, off, length):
     mixer, _, mlp_kind = kind.partition("+")
     new_cache = cache
-    h = L.block_norm(cfg, p["norm1"], x)
+    with jax.named_scope("norm"):
+        h = L.block_norm(cfg, p["norm1"], x)
     if mixer == "attn":
         out, ck, cv = L.attention_prefill_chunk_paged(
             p["mixer"], h, cfg, cache["k"], cache["v"], pt_row, off, length,
@@ -851,8 +853,10 @@ def _layer_chunk_paged(cfg, kind, p, x, cache, pt_row, slot, off, length):
         raise ValueError(f"prefill_chunk_paged: unsupported mixer {mixer!r} "
                          "(see supports_paged_kv)")
     if mlp_kind == "mlp":
-        h = L.block_norm(cfg, p["norm2"], x)
-        x = x + L.mlp(p["mlp"], h, cfg.activation)
+        with jax.named_scope("norm"):
+            h = L.block_norm(cfg, p["norm2"], x)
+        with jax.named_scope("mlp"):
+            x = x + L.mlp(p["mlp"], h, cfg.activation)
     elif mlp_kind:
         raise ValueError("prefill_chunk_paged: MoE layers are not chunk-safe "
                          "(padding rows would consume expert capacity)")
@@ -871,26 +875,27 @@ def prefill_chunk_paged(cfg: ModelConfig, params, pool: dict, slot,
     so one compiled kernel serves every (lane, offset, tail-length).
     """
     assert tokens.shape[0] == 1, "prefill_chunk_paged operates on one lane"
-    slot = jnp.asarray(slot, jnp.int32)
-    length = jnp.asarray(length, jnp.int32)
-    off = pool["pos"][slot]
-    pt_row = pool["page_table"][slot]
-    x = params["tok_embed"][tokens]
-    x = shard(x, ("batch", None, None))
+    with jax.named_scope("prefill_chunk"):
+        slot = jnp.asarray(slot, jnp.int32)
+        length = jnp.asarray(length, jnp.int32)
+        off = pool["pos"][slot]
+        pt_row = pool["page_table"][slot]
+        x = params["tok_embed"][tokens]
+        x = shard(x, ("batch", None, None))
 
-    def body(x, xs):
-        p_period, c_period = xs
-        new_c = {}
-        for i, kind in enumerate(cfg.block_pattern):
-            keyname = f"{i:02d}_{kind}"
-            x, new_c[keyname] = _layer_chunk_paged(cfg, kind, p_period[keyname], x,
-                                                   c_period[keyname], pt_row, slot,
-                                                   off, length)
-        return x, new_c
+        def body(x, xs):
+            p_period, c_period = xs
+            new_c = {}
+            for i, kind in enumerate(cfg.block_pattern):
+                keyname = f"{i:02d}_{kind}"
+                x, new_c[keyname] = _layer_chunk_paged(cfg, kind, p_period[keyname], x,
+                                                       c_period[keyname], pt_row, slot,
+                                                       off, length)
+            return x, new_c
 
-    _, new_blocks = lax.scan(body, x, (params["blocks"], pool["blocks"]))
-    return {"pos": pool["pos"].at[slot].add(length),
-            "page_table": pool["page_table"], "blocks": new_blocks}
+        _, new_blocks = lax.scan(body, x, (params["blocks"], pool["blocks"]))
+        return {"pos": pool["pos"].at[slot].add(length),
+                "page_table": pool["page_table"], "blocks": new_blocks}
 
 
 def paged_set_lane(pool: dict, slot, row, pos0) -> dict:
