@@ -250,6 +250,7 @@ def attention_prefill_chunk_paged(
     cfg: ModelConfig,
     k_pool: jax.Array,
     v_pool: jax.Array,
+    layer: jax.Array,
     pt_row: jax.Array,
     off: jax.Array,
     length: jax.Array,
@@ -258,11 +259,14 @@ def attention_prefill_chunk_paged(
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Fixed-shape chunk prefill straight into a lane's pages.
 
-    x: (1, C, d) normed hidden states (rows >= ``length`` are padding); pt_row:
-    (num_pages,) int32, mapped far enough to cover ``off + length`` tokens.  The
-    chunk's K/V rows scatter to their absolute (block, offset) slots — padding
-    and out-of-capacity rows route to scratch block 0 — then each query ``i``
-    attends to positions ``t <= off + i`` through the gathered page view.  The
+    x: (1, C, d) normed hidden states (rows >= ``length`` are padding);
+    k_pool/v_pool: every layer's blocks, (P, num_blocks, page_size, KV, hd), of
+    which this call touches layer ``layer`` only; pt_row: (num_pages,) int32,
+    mapped far enough to cover ``off + length`` tokens.  The chunk's K/V rows
+    scatter to their absolute (layer, block, offset) slots — padding and
+    out-of-capacity rows route to scratch block 0 — then each query ``i``
+    attends to positions ``t <= off + i`` through the lane's pages, gathered
+    straight from the stack (never a slice of the layer's whole pool).  The
     suffix of a prefix-shared admission runs through this path attending to the
     *shared* pages in place: zero prefix KV copies.  Returns
     (out (1, C, d_model), k_pool', v_pool').
@@ -281,7 +285,7 @@ def attention_prefill_chunk_paged(
         if use_rope:
             q = rope(q, positions, cfg.rope_theta)
             k = rope(k, positions, cfg.rope_theta)
-    ps = k_pool.shape[1]
+    ps = k_pool.shape[2]
     num_pages = pt_row.shape[0]
     cap = num_pages * ps
     rows = off + jnp.arange(Cn)
@@ -290,11 +294,11 @@ def attention_prefill_chunk_paged(
         page = jnp.clip(rows // ps, 0, num_pages - 1)
         blk = jnp.where(valid, pt_row[page], 0)               # padding -> scratch
         slot = rows % ps
-        k_pool = k_pool.at[blk, slot].set(k[0].astype(k_pool.dtype))
-        v_pool = v_pool.at[blk, slot].set(v[0].astype(v_pool.dtype))
+        k_pool = k_pool.at[layer, blk, slot].set(k[0].astype(k_pool.dtype))
+        v_pool = v_pool.at[layer, blk, slot].set(v[0].astype(v_pool.dtype))
     with jax.named_scope("attn"):
-        kg = k_pool[pt_row][None].reshape(1, cap, KV, hd)
-        vg = v_pool[pt_row][None].reshape(1, cap, KV, hd)
+        kg = k_pool[layer, pt_row][None].reshape(1, cap, KV, hd)
+        vg = v_pool[layer, pt_row][None].reshape(1, cap, KV, hd)
         mask = jnp.arange(cap)[None, :] <= rows[:, None]      # (C, cap)
         qg = q.reshape(B, Cn, KV, G, hd)
         out = _plain_attention(qg, kg, vg, mask[None, None, None],

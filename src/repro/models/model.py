@@ -827,27 +827,34 @@ def init_paged_pool(cfg: ModelConfig, params, max_lanes: int, num_blocks: int,
             "blocks": blocks}
 
 
-def _layer_chunk_paged(cfg, kind, p, x, cache, pt_row, slot, off, length):
+def _layer_chunk_paged(cfg, kind, p, x, cache, layer, pt_row, slot, off, length):
+    """One layer of the paged chunk.  ``cache``: this kind's leaves for every
+    period, (P, ...); only ``[layer, ...]`` is read or written."""
     mixer, _, mlp_kind = kind.partition("+")
     new_cache = cache
     with jax.named_scope("norm"):
         h = L.block_norm(cfg, p["norm1"], x)
     if mixer == "attn":
         out, ck, cv = L.attention_prefill_chunk_paged(
-            p["mixer"], h, cfg, cache["k"], cache["v"], pt_row, off, length,
+            p["mixer"], h, cfg, cache["k"], cache["v"], layer, pt_row, off, length,
             use_rope=_use_rope(cfg))
         x = x + out
         new_cache = dict(cache, k=ck, v=cv)
     elif mixer in ("mamba", "mlstm", "slstm"):
         step_fn = {"mamba": L.mamba_step, "mlstm": L.mlstm_step,
                    "slstm": L.slstm_step}[mixer]
-        state = jax.tree.map(lambda s: lax.dynamic_slice_in_dim(s, slot, 1, axis=0),
-                             cache)
+        zero = jnp.zeros((), jnp.int32)
+
+        def start(c):
+            return (layer, slot) + (zero,) * (c.ndim - 2)
+
+        state = jax.tree.map(
+            lambda c: lax.dynamic_slice(c, start(c), (1, 1) + c.shape[2:])[0], cache)
         out, state = _recurrent_chunk(step_fn, p["mixer"], h, cfg, state, length)
         x = x + out
         new_cache = jax.tree.map(
-            lambda c, s: lax.dynamic_update_slice_in_dim(c, s.astype(c.dtype),
-                                                         slot, axis=0),
+            lambda c, s: lax.dynamic_update_slice(c, s[None].astype(c.dtype),
+                                                  start(c)),
             cache, state)
     else:
         raise ValueError(f"prefill_chunk_paged: unsupported mixer {mixer!r} "
@@ -873,6 +880,11 @@ def prefill_chunk_paged(cfg: ModelConfig, params, pool: dict, slot,
     possibly *shared* pages — plus the chunk's own causal keys), and recurrent
     state updates its dense lane row in place.  ``slot``/``length`` are traced,
     so one compiled kernel serves every (lane, offset, tail-length).
+
+    The layer scan carries the whole pool and indexes it by layer: slicing each
+    layer's pool in as the scan's ``xs`` and writing it back as its ``ys``
+    would move every block of every layer per chunk, where a chunk writes C
+    rows a layer and reads only the lane's pages.
     """
     assert tokens.shape[0] == 1, "prefill_chunk_paged operates on one lane"
     with jax.named_scope("prefill_chunk"):
@@ -883,17 +895,20 @@ def prefill_chunk_paged(cfg: ModelConfig, params, pool: dict, slot,
         x = params["tok_embed"][tokens]
         x = shard(x, ("batch", None, None))
 
-        def body(x, xs):
-            p_period, c_period = xs
+        def body(carry, xs):
+            x, blocks = carry
+            p_period, layer = xs
             new_c = {}
             for i, kind in enumerate(cfg.block_pattern):
                 keyname = f"{i:02d}_{kind}"
                 x, new_c[keyname] = _layer_chunk_paged(cfg, kind, p_period[keyname], x,
-                                                       c_period[keyname], pt_row, slot,
-                                                       off, length)
-            return x, new_c
+                                                       blocks[keyname], layer, pt_row,
+                                                       slot, off, length)
+            return (x, new_c), None
 
-        _, new_blocks = lax.scan(body, x, (params["blocks"], pool["blocks"]))
+        (_, new_blocks), _ = lax.scan(
+            body, (x, pool["blocks"]),
+            (params["blocks"], jnp.arange(cfg.n_periods, dtype=jnp.int32)))
         return {"pos": pool["pos"].at[slot].add(length),
                 "page_table": pool["page_table"], "blocks": new_blocks}
 

@@ -180,6 +180,126 @@ def test_meshed_pallas_decode_rejects_indivisible_kv_heads():
         ops.paged_decode_attention(q, pool, pool, pt, vl, force_pallas=True)
 
 
+# ------------------------------------------------------------ the chunk program
+
+def _chunk_sliced(cfg, params, pool, slot, tokens, length):
+    """The chunk program in the form that slices each layer's pool in as the
+    layer scan's ``xs`` and writes it back whole as its ``ys``: the reference
+    the carried form must match bit for bit."""
+    slot = jnp.asarray(slot, jnp.int32)
+    length = jnp.asarray(length, jnp.int32)
+    off = pool["pos"][slot]
+    pt_row = pool["page_table"][slot]
+    zero = jnp.zeros((), jnp.int32)
+
+    def body(x, xs):
+        p_period, c_period = xs
+        new_c = {}
+        for i, kind in enumerate(cfg.block_pattern):
+            key = f"{i:02d}_{kind}"
+            one = jax.tree.map(lambda c: c[None], c_period[key])
+            x, out = M._layer_chunk_paged(cfg, kind, p_period[key], x, one, zero,
+                                          pt_row, slot, off, length)
+            new_c[key] = jax.tree.map(lambda c: c[0], out)
+        return x, new_c
+
+    _, blocks = jax.lax.scan(body, params["tok_embed"][tokens],
+                             (params["blocks"], pool["blocks"]))
+    return {"pos": pool["pos"].at[slot].add(length),
+            "page_table": pool["page_table"], "blocks": blocks}
+
+
+_SLICED = jax.jit(_chunk_sliced, static_argnums=(0,))
+
+
+def _hybrid():
+    return get_config("jamba_v0_1_52b").reduced(
+        n_periods=2, block_pattern=("mamba+mlp", "attn+mlp"))
+
+
+def _paged_leaves(pool):
+    return {key: c for key, c in pool["blocks"].items() if M._paged_kind(key[3:])}
+
+
+@pytest.mark.parametrize("make_cfg", [
+    lambda: get_config("qwen3_1_7b").reduced(n_periods=2),
+    _hybrid,                                       # mamba + attention
+    lambda: get_config("xlstm_350m").reduced(n_periods=2),  # mlstm + slstm
+], ids=["qwen3", "hybrid", "xlstm"])
+def test_chunk_program_carries_the_pool_bitwise(make_cfg):
+    """Several chunks on two lanes, the second mapping the first's two full
+    prefix pages and prefilling its suffix on top of them: the carried pool
+    equals the sliced form's bit for bit, and a chunk writes no block its lane
+    does not map (scratch block 0 aside) and no other lane's state."""
+    from repro.engine.worker import _paged_chunk
+
+    cfg = make_cfg()
+    params = M.init_params(cfg, KEY)
+    ps, C, pages = 8, 8, 5
+    pool = M.init_paged_pool(cfg, None, 2, 11, ps, pages)
+    leaves, tree = jax.tree.flatten(pool["blocks"])
+    keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
+    pool["blocks"] = jax.tree.unflatten(tree, [
+        jax.random.normal(k, x.shape, x.dtype) for k, x in zip(keys, leaves)])
+    rows = {0: [1, 2, 3, 4, 0], 1: [1, 2, 5, 6, 0]}    # lane 1 shares blocks 1, 2
+    rng = np.random.default_rng(0)
+    prompt0 = rng.integers(1, cfg.vocab, 27)
+    prompts = {0: prompt0, 1: np.concatenate([prompt0[:16],
+                                              rng.integers(1, cfg.vocab, 13)])}
+    # (lane, first position, valid tokens): lane 1 starts once lane 0 has
+    # written its first 16 positions, and the two lanes' chunks interleave
+    plan = [(0, 0, 8), (0, 8, 8), (1, 16, 8), (0, 16, 8), (1, 24, 5), (0, 24, 3)]
+
+    def chunk(prog, pool, slot, start, n):
+        tok = np.zeros((1, C), np.int32)
+        tok[0, :n] = prompts[slot][start:start + n]
+        return prog(cfg, params, pool, jnp.asarray(slot, jnp.int32),
+                    jnp.asarray(tok), jnp.asarray(n, jnp.int32))
+
+    ours = ref = M.paged_set_lane(M.paged_set_lane(pool, 0, rows[0], 0),
+                                  1, rows[1], 16)
+    ref = jax.tree.map(jnp.copy, ref)              # ``_paged_chunk`` donates
+    for slot, start, n in plan:
+        before = jax.tree.map(np.asarray, ours)
+        ours = chunk(_paged_chunk, ours, slot, start, n)
+        ref = chunk(_SLICED, ref, slot, start, n)
+        after = jax.tree.map(np.asarray, ours)
+        jax.tree.map(np.testing.assert_array_equal, after, jax.tree.map(np.asarray, ref))
+        other = 1 - slot
+        keep = [b for b in range(1, 11) if b not in rows[slot]]
+        if start >= 16:
+            keep += [1, 2]                         # written ahead of the chunk
+        for key, c in _paged_leaves(after).items():
+            for name in c:
+                np.testing.assert_array_equal(
+                    c[name][:, keep], _paged_leaves(before)[key][name][:, keep])
+        for key, c in after["blocks"].items():
+            if not M._paged_kind(key[3:]):
+                for name in c:
+                    np.testing.assert_array_equal(
+                        c[name][:, other], before["blocks"][key][name][:, other])
+    assert [int(p) for p in ours["pos"]] == [27, 29]
+
+
+def test_chunk_program_keeps_no_copy_of_the_pool():
+    """Guard against the pool's round trip through the layer scan: the compiled
+    chunk program's temporaries stay below the bytes of the paged blocks (the
+    sliced form copies every layer's blocks and needs more than all of them)."""
+    from repro.engine.worker import _paged_chunk
+
+    cfg = get_config("qwen3_1_7b").reduced(n_periods=4)
+    params = M.init_params(cfg, KEY)
+    pool = jax.eval_shape(lambda: M.init_paged_pool(cfg, None, 4, 33, 16, 8))
+    i32 = jax.ShapeDtypeStruct((), jnp.int32)
+    compiled = _paged_chunk.lower(cfg, params, pool, i32,
+                                  jax.ShapeDtypeStruct((1, 32), jnp.int32),
+                                  i32).compile()
+    pool_bytes = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree.leaves(_paged_leaves(pool)))
+    assert pool_bytes == 4_325_376
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+
+
 # ------------------------------------------------------- bitwise token parity
 
 def test_paged_decode_bitwise_matches_dense(setup):
